@@ -81,10 +81,14 @@ def rows():
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          env.get("PYTHONPATH", "")])
+    # a CPU semantics harness (8 forced host devices): it must not take
+    # the accelerator this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", _WORKER], env=env,
                           capture_output=True, text=True, timeout=1800)
     if proc.returncode != 0:
-        return [("fig4/error", -1.0, proc.stderr[-200:])]
+        raise RuntimeError(f"fig4 worker failed (rc {proc.returncode}):\n"
+                           f"{proc.stderr[-2000:]}")
     line = [l for l in proc.stdout.splitlines() if l.startswith("RESULT ")][0]
     res = json.loads(line[len("RESULT "):])
     out = []

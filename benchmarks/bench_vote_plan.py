@@ -107,11 +107,15 @@ def rows():
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          env.get("PYTHONPATH", "")])
+    # a CPU semantics harness (8 forced host devices): it must not take
+    # the accelerator this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run(
         [sys.executable, "-c", _WORKER, json.dumps(SWEEP_BUCKET_BYTES)],
         env=env, capture_output=True, text=True, timeout=1800)
     if proc.returncode != 0:
-        return [("vote_plan/error", -1.0, proc.stderr[-200:])]
+        raise RuntimeError(f"vote_plan worker failed (rc {proc.returncode}):\n"
+                           f"{proc.stderr[-2000:]}")
     line = [ln for ln in proc.stdout.splitlines()
             if ln.startswith("RESULT ")][0]
     res = json.loads(line[len("RESULT "):])

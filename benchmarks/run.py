@@ -29,6 +29,7 @@ import argparse
 import sys
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import recorder as obs
 
 
@@ -45,6 +46,7 @@ def main() -> None:
                     help="also write the produced rows to this JSON file")
     obs.add_trace_arg(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     rec = obs.activate_trace(args)
 
     from benchmarks import (bench_attacks, bench_codecs, bench_comm,

@@ -2,8 +2,8 @@
 with SIGNUM + majority vote, with checkpointing every 100 steps.
 
 The model is a glm4-family transformer scaled to ~100M params
-(12 layers, d_model=512, vocab 32k). On CPU this takes a few minutes; on a
-real mesh the identical step runs under launch/train.py.
+(12 layers, d_model=512, vocab 32k). On CPU this takes a few minutes; on
+one TPU chip the same step runs under launch/train.py.
 
     PYTHONPATH=src python examples/train_lm.py [--steps 300]
 """

@@ -1,37 +1,15 @@
-"""Version-compat layer over the JAX APIs this repo targets.
+"""The mesh / shard_map surface the repo calls, on the installed JAX (0.9).
 
-The codebase is written against the modern mesh/shard_map surface
-(``jax.shard_map`` with ``axis_names=``, ``jax.sharding.get_abstract_mesh``,
-``jax.sharding.AxisType``, ``jax.make_mesh(..., axis_types=...)``,
-``jax.set_mesh``). Older installs (e.g. 0.4.x) lack all of these; this
-module maps each call onto whatever the installed JAX provides so the rest
-of ``src/`` never branches on a version string at a call site.
-
-Every shim is behaviour-preserving where the old API can express the new
-semantics, and degrades to a documented no-op where it cannot:
-
-* ``shard_map`` — new kwarg style maps to the legacy positional signature
-  (``axis_names`` -> ``auto`` complement, ``check_vma`` -> ``check_rep``).
-  On legacy JAX a *nested* shard_map (manual sub-region inside a manual
-  region) is executed inline: the nesting exists upstream only to steer the
-  partitioner away from fp32 replication (see majority_vote.make_gather_vote);
-  the collectives inside are equally valid in the enclosing manual region.
-* ``get_abstract_mesh`` — on legacy JAX, resolves from this module's own
-  tracing-context stack (maintained by the ``shard_map`` / ``set_mesh``
-  shims), so ``distributed.sharding.shard`` can keep asking "what mesh am I
-  under, and which axes are Manual here?" uniformly.
-* ``make_mesh`` — drops ``axis_types`` where unsupported (legacy meshes are
-  implicitly Auto, which is what every caller passes).
+Each name here is a thin pass-through to the JAX API of the same name.
+Call sites import them from one place; rewriting them to bare ``jax.*``
+is open work (ROADMAP C2).
 """
 from __future__ import annotations
 
-import contextlib
-import dataclasses
-import enum
-import threading
-from typing import Any, Dict, Optional, Sequence, Set, Tuple
+from typing import Any, Dict, Optional, Sequence, Set
 
 import jax
+import jax.numpy as jnp
 
 __all__ = [
     "AxisType", "all_gather", "axis_size", "cost_analysis_dict",
@@ -39,350 +17,57 @@ __all__ = [
     "tree_leaves_with_path",
 ]
 
-_HAS_NEW_SHARD_MAP = hasattr(jax, "shard_map")
-_HAS_GET_ABSTRACT_MESH = hasattr(jax.sharding, "get_abstract_mesh")
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
-#: legacy partial-auto shard_map aborts the SPMD partitioner on a lax.scan
-#: whose xs derive from manually-sharded operands (the microbatch loop);
-#: scans over replicated xs (the depth scan) are fine. Callers unroll the
-#: affected loop when this is False.
-SCAN_OVER_MANUAL_XS_SAFE = _HAS_NEW_SHARD_MAP
-
-# Modern JAX defaults jax_threefry_partitionable=True; legacy defaults False,
-# under which random.normal computed under a dim-0 out_sharding yields
-# DIFFERENT values than the same call unsharded (observed on 0.4.37: mesh
-# materialize_state vs single-process init diverged on every 'model'-dim-0
-# param). Placement-invariant RNG is a correctness requirement for the
-# mesh-vs-flat reference checks, so align the legacy default.
-try:
-    if not jax.config.jax_threefry_partitionable:
-        jax.config.update("jax_threefry_partitionable", True)
-except AttributeError:
-    pass
-
-
-# ---------------------------------------------------------------------------
-# AxisType
-# ---------------------------------------------------------------------------
-
-if _HAS_AXIS_TYPE:
-    AxisType = jax.sharding.AxisType
-else:
-    class AxisType(enum.Enum):  # type: ignore[no-redef]
-        """Stand-in for ``jax.sharding.AxisType`` on legacy JAX (where every
-        mesh axis is implicitly Auto and Manual-ness comes from shard_map)."""
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-# ---------------------------------------------------------------------------
-# mesh-context tracking (legacy path)
-# ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass(frozen=True)
-class _MeshView:
-    """The subset of the AbstractMesh surface the repo consumes:
-    ``empty`` / ``axis_names`` / ``axis_sizes`` / ``axis_types``."""
-
-    axis_names: Tuple[str, ...]
-    axis_sizes: Tuple[int, ...]
-    axis_types: Tuple[Any, ...]
-    concrete: Any = None  # the jax.sharding.Mesh, when known
-
-    @property
-    def empty(self) -> bool:
-        return not self.axis_names
-
-    @property
-    def shape(self) -> Dict[str, int]:
-        return dict(zip(self.axis_names, self.axis_sizes))
-
-
-_EMPTY_VIEW = _MeshView((), (), ())
-
-
-class _ContextStack(threading.local):
-    def __init__(self):
-        self.stack = []
-
-
-_CTX = _ContextStack()
-
-
-def _view_of(mesh, manual: Set[str]) -> _MeshView:
-    names = tuple(mesh.axis_names)
-    sizes = tuple(mesh.devices.shape) if hasattr(mesh, "devices") \
-        else tuple(mesh.axis_sizes)
-    types = tuple(AxisType.Manual if n in manual else AxisType.Auto
-                  for n in names)
-    return _MeshView(names, sizes, types, concrete=mesh)
-
-
-@contextlib.contextmanager
-def _pushed(view: _MeshView):
-    _CTX.stack.append(view)
-    try:
-        yield
-    finally:
-        _CTX.stack.pop()
-
-
-def get_abstract_mesh():
-    """The mesh of the current tracing context (or an empty view).
-
-    New JAX: delegates to ``jax.sharding.get_abstract_mesh``. Legacy JAX:
-    returns the innermost mesh recorded by this module's ``shard_map`` /
-    ``set_mesh`` shims, falling back to the ``with mesh:`` thread-resource
-    context. The result always exposes ``empty``, ``axis_names``,
-    ``axis_sizes`` and ``axis_types``.
-    """
-    if _HAS_GET_ABSTRACT_MESH:
-        return jax.sharding.get_abstract_mesh()
-    if _CTX.stack:
-        return _CTX.stack[-1]
-    env_mesh = getattr(
-        getattr(jax._src.mesh.thread_resources, "env", None),
-        "physical_mesh", None)
-    if env_mesh is not None and env_mesh.devices.size:
-        return _view_of(env_mesh, manual=set())
-    return _EMPTY_VIEW
-
-
-def _current_concrete_mesh():
-    m = get_abstract_mesh()
-    if isinstance(m, _MeshView):
-        return m.concrete
-    if m is None or m.empty:
-        return None
-    return m
-
-
-def _manual_axes_here() -> Set[str]:
-    if _CTX.stack:
-        v = _CTX.stack[-1]
-        return {n for n, t in zip(v.axis_names, v.axis_types)
-                if t == AxisType.Manual}
-    return set()
-
-
-# ---------------------------------------------------------------------------
-# mesh construction / activation
-# ---------------------------------------------------------------------------
+AxisType = jax.sharding.AxisType
+get_abstract_mesh = jax.sharding.get_abstract_mesh
+set_mesh = jax.set_mesh
+axis_size = jax.lax.axis_size
+tree_leaves_with_path = jax.tree.leaves_with_path
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, axis_types: Optional[Sequence[Any]] = None, **kw):
-    """``jax.make_mesh`` that tolerates installs without ``axis_types``
-    (legacy meshes are implicitly Auto — the only type callers pass)."""
-    try:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
-                             axis_types=axis_types, **kw)
-    except TypeError:
-        return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kw)
-
-
-@contextlib.contextmanager
-def set_mesh(mesh):
-    """``jax.set_mesh`` fallback: activates `mesh` for sharding resolution.
-
-    On legacy JAX this both enters the ``with mesh:`` resource context (so
-    bare-PartitionSpec ``with_sharding_constraint`` resolves) and records the
-    mesh for :func:`get_abstract_mesh`.
-    """
-    if hasattr(jax, "set_mesh"):
-        with jax.set_mesh(mesh):
-            yield mesh
-        return
-    with mesh, _pushed(_view_of(mesh, manual=set())):
-        yield mesh
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=axis_types, **kw)
 
 
 def shard_map(f, *, mesh=None, in_specs, out_specs,
               axis_names: Optional[Set[str]] = None, check_vma: bool = False):
-    """New-style ``jax.shard_map`` (kwargs, partial-manual via `axis_names`)
-    on any JAX.
-
-    Legacy mapping: ``axis_names`` becomes the complement ``auto=`` set and
-    ``check_vma`` becomes ``check_rep``. When `mesh` is omitted it is taken
-    from the active context (set by an enclosing shard_map / set_mesh).
-    A nested call inside an already-manual region runs `f` inline on legacy
-    JAX — legacy partial-auto nesting aborts the SPMD partitioner, and the
-    nesting is a partitioner hint, not a semantic requirement.
-    """
-    if _HAS_NEW_SHARD_MAP:
-        kwargs = dict(in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        if mesh is not None:
-            kwargs["mesh"] = mesh
-        return jax.shard_map(f, **kwargs)
-
-    from jax.experimental.shard_map import shard_map as _legacy_shard_map
-
-    already_manual = _manual_axes_here()
-    if mesh is None and already_manual:
-        # nested manual sub-region: run inline (see docstring)
-        return f
-    concrete = mesh if mesh is not None else _current_concrete_mesh()
-    if concrete is None:
-        raise ValueError(
-            "compat.shard_map: no mesh given and none active in context")
-    all_axes = set(concrete.axis_names)
-    manual = set(axis_names) if axis_names is not None else all_axes
-    auto = frozenset(all_axes - manual)
-
-    def traced(*args, **kw):
-        with _pushed(_view_of(concrete, manual=manual)):
-            return f(*args, **kw)
-
-    return _legacy_shard_map(traced, concrete, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=check_vma,
-                             auto=auto)
-
-
-# ---------------------------------------------------------------------------
-# small API deltas
-# ---------------------------------------------------------------------------
-
-
-def _partial_auto_active() -> bool:
-    """True when tracing inside a legacy shard_map that left some mesh axes
-    auto (the configuration whose all-gather lowering aborts the legacy
-    SPMD partitioner)."""
-    if _HAS_NEW_SHARD_MAP or not _CTX.stack:
-        return False
-    v = _CTX.stack[-1]
-    return any(t != AxisType.Manual for t in v.axis_types)
+    """``jax.shard_map``; `mesh` defaults to the active one and
+    `axis_names` (the manual axes) to all of its axes."""
+    kwargs = dict(in_specs=in_specs, out_specs=out_specs,
+                  check_vma=check_vma)
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    if mesh is not None:
+        kwargs["mesh"] = mesh
+    return jax.shard_map(f, **kwargs)
 
 
 def axis_index(axis_name: str, like=None):
-    """``jax.lax.axis_index`` that survives legacy partial-auto shard_map.
-
-    The native op lowers to a PartitionId instruction the legacy SPMD
-    partitioner rejects inside partial-auto regions; only psum/psum_scatter
-    lower there, so the index is recovered as
-    ``psum_scatter(arange(m)) / m`` — replica r receives
-    ``sum_replicas(arange(m)[r]) = m * r``. The partitioner also aborts on
-    collectives over pure constants (no manual sharding to inherit), so
-    `like` — any traced array from the surrounding manual region — anchors
-    the operand; it is required on the emulated path.
-    """
-    import jax.numpy as jnp
-    if not _partial_auto_active():
-        return jax.lax.axis_index(axis_name)
-    if like is None:
-        raise ValueError(
-            "compat.axis_index inside a legacy partial-auto region needs a "
-            "`like=` traced array to anchor the emulation's sharding")
-    m = axis_size(axis_name)
-    anchor = (jnp.ravel(like)[0] * 0).astype(jnp.int32)
-    row = jnp.arange(m, dtype=jnp.int32) + anchor
-    scattered = jax.lax.psum_scatter(row, axis_name, scatter_dimension=0,
-                                     tiled=True)          # (1,) = m * index
-    return (scattered[0] // m).astype(jnp.int32)
+    """``jax.lax.axis_index``; `like` is accepted and unused."""
+    del like
+    return jax.lax.axis_index(axis_name)
 
 
 def all_gather(x, axis_name: str, *, axis: int = 0, tiled: bool = False):
-    """``jax.lax.all_gather`` that survives legacy partial-auto shard_map.
-
-    Inside a legacy partial-auto region the native all-gather lowering hits
-    ``Check failed: IsManualSubgroup()`` in the SPMD partitioner (hard
-    abort, observed on 0.4.37); there it is emulated as a one-hot
-    ``psum`` — each replica contributes its block at its own index and the
-    sum reassembles the gather. Everywhere else the native op is used.
-    """
-    if not _partial_auto_active():
-        return jax.lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
-    import jax.numpy as jnp
-    m = axis_size(axis_name)
-    idx = axis_index(axis_name, like=x)
-    mask = jax.lax.broadcasted_iota(
-        jnp.int32, (m,) + (1,) * x.ndim, 0) == idx
-    buf = jnp.where(mask, x[None], jnp.zeros((), x.dtype))
-    stacked = jax.lax.psum(buf, axis_name)          # (m,) + x.shape
-    stacked = jnp.moveaxis(stacked, 0, axis)
-    if not tiled:
-        return stacked
-    shape = list(x.shape)
-    shape[axis] = m * shape[axis]
-    return stacked.reshape(shape)
+    return jax.lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
 
 
 def with_sharding_constraint(x, spec):
-    """``jax.lax.with_sharding_constraint`` with a bare PartitionSpec on any
-    JAX. Legacy JAX resolves bare specs only under ``with mesh:``; when the
-    compat context knows the concrete mesh the spec is bound to a
-    NamedSharding, and an unconstrained spec (or no known mesh) is a no-op
-    rather than an error."""
+    """``jax.lax.with_sharding_constraint`` with a bare PartitionSpec; a
+    fully unconstrained spec is a no-op (it needs no active mesh)."""
     if all(e is None for e in spec):
         return x
-    if _HAS_GET_ABSTRACT_MESH:
-        return jax.lax.with_sharding_constraint(x, spec)
-    concrete = _current_concrete_mesh()
-    if concrete is None:
-        return x
-    return jax.lax.with_sharding_constraint(
-        x, jax.sharding.NamedSharding(concrete, spec))
-
-
-def zeros_like_traced(x, dtype=None):
-    """``jnp.zeros(x.shape, dtype)`` anchored to `x`'s sharding inside
-    legacy partial-auto shard_map (a pure-constant zeros tensor feeding the
-    scan/collective machinery there trips the same IsManualSubgroup abort
-    as constant collectives); a plain constant zeros everywhere else."""
-    import jax.numpy as jnp
-    dtype = dtype or x.dtype
-    if not _partial_auto_active():
-        return jnp.zeros(x.shape, dtype)
-    return (x * jnp.zeros((), x.dtype)).astype(dtype)
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
 def pad_trailing(x, count: int):
-    """Zero-pad the last dim by `count`, safely inside legacy partial-auto
-    shard_map (``jnp.pad``'s constant-pad lowering hits the same
-    IsManualSubgroup abort as constant collectives; concatenating zeros
-    anchored to the operand's sharding does not)."""
-    import jax.numpy as jnp
+    """Zero-pad the last dim by `count` (`x` itself when count == 0)."""
     if count == 0:
         return x
-    if not _partial_auto_active():
-        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, count)])
-    anchor = (jnp.ravel(x)[0] * 0).astype(x.dtype)
-    zeros = jnp.zeros(x.shape[:-1] + (count,), x.dtype) + anchor
-    return jnp.concatenate([x, zeros], axis=-1)
-
-
-def axis_size(name: str) -> int:
-    """``jax.lax.axis_size`` fallback: size of a named mapped axis inside
-    shard_map (``psum(1)`` constant-folds to the axis size on legacy JAX)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
-
-def tree_leaves_with_path(tree):
-    """``jax.tree.leaves_with_path`` fallback via ``jax.tree_util``."""
-    if hasattr(jax.tree, "leaves_with_path"):
-        return jax.tree.leaves_with_path(tree)
-    return jax.tree_util.tree_leaves_with_path(tree)
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, count)])
 
 
 def cost_analysis_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` as a flat dict on every JAX version
-    (legacy returns a one-entry list of per-device dicts)."""
-    cost = compiled.cost_analysis()
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        return dict(cost[0]) if cost else {}
-    return dict(cost)
+    """``compiled.cost_analysis()`` as a dict ({} when unavailable)."""
+    return dict(compiled.cost_analysis() or {})

@@ -48,10 +48,7 @@ MODES = ("none", "sign_flip", "random", "zero", "colluding", "blind")
 
 def replica_index(axis_names: Sequence[str], like=None) -> jax.Array:
     """Linear index of this replica over the (manual) vote axes.
-
-    `like` anchors the legacy-JAX emulation's sharding (see
-    ``compat.axis_index``); pass any traced array from the manual region.
-    """
+    `like` is accepted and unused."""
     idx = jnp.int32(0)
     for name in axis_names:
         idx = idx * compat.axis_size(name) + compat.axis_index(name,

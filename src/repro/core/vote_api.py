@@ -83,9 +83,7 @@ def count_bytes(n_voters: int) -> int:
 def pad_last(x: jax.Array, multiple: int) -> Tuple[jax.Array, int]:
     """Zero-pad the LAST dim to a multiple; returns (padded, original_n).
 
-    Routed through ``compat.pad_trailing`` so padding stays safe inside
-    legacy partial-auto shard_map (raw ``jnp.pad``'s constant-pad
-    lowering aborts there). This is THE padding helper — `vote_engine`,
+    This is THE padding helper — `vote_engine`,
     `vote_plan`, `sign_compress` and the virtual mesh all delegate here,
     so the wire's pad semantics cannot silently diverge per module."""
     n = x.shape[-1]

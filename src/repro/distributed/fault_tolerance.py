@@ -53,7 +53,7 @@ def simulate_stragglers(signs: jax.Array, prev_signs: jax.Array,
 def straggler_mask_for(axis_names: Sequence[str], n_stale: int,
                        like=None) -> jax.Array:
     """First `n_stale` replicas along the vote axes are stale this step.
-    `like` anchors the legacy-JAX index emulation (compat.axis_index)."""
+    `like` is accepted and unused."""
     from repro.core.byzantine import replica_index
     return replica_index(axis_names, like=like) < n_stale
 

@@ -61,11 +61,6 @@ def shard(x: jax.Array, *spec) -> jax.Array:
     fixed = P(*(fix(e, d) for e, d in zip(spec, x.shape)))
     if all(e is None for e in fixed):
         return x
-    # legacy JAX resolves bare PartitionSpecs only under `with mesh:`; when
-    # the compat layer knows the concrete mesh, bind it explicitly.
-    concrete = getattr(mesh, "concrete", None)
-    if concrete is not None:
-        fixed = jax.sharding.NamedSharding(concrete, fixed)
     return jax.lax.with_sharding_constraint(x, fixed)
 
 

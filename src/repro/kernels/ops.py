@@ -1,8 +1,8 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 Handle arbitrary 1-D/N-D inputs (pad + reshape to the kernels' tiled 2-D
-layout), and dispatch ``interpret=True`` automatically on non-TPU backends
-so the same call sites work in CPU tests and on real hardware.
+layout). The kernels compile for the TPU; on the CPU backend (tests) they
+run in Pallas interpret mode, and any other backend is an error.
 
 Every wrapper counts its invocations under ``kernel.launches.<name>``
 in the global :data:`repro.obs.COUNTERS` registry (one wrapper call =
@@ -83,7 +83,12 @@ LAUNCHES = _LaunchShim()
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(
+            f"the Pallas vote kernels compile for the TPU and interpret on "
+            f"the CPU only; the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def _to_2d(flat: jax.Array) -> Tuple[jax.Array, int]:
@@ -121,7 +126,7 @@ def fused_majority(x: jax.Array) -> jax.Array:
     ONE pass (fused sign+bitpack+popcount; ties and padding -> sign(0)=+1)."""
     _launch("fused_majority")
     m, n = x.shape
-    rem = (-n) % (128 * PACK)
+    rem = (-n) % TILE
     if rem:
         x = jnp.pad(x, ((0, 0), (0, rem)))
     packed = _fv.fused_majority_2d(x, interpret=_interpret())

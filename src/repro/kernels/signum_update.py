@@ -23,23 +23,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-PACK = 32
-ROWS = 8
-WORDS = 128
+from repro.kernels.bitpack import (PACK, ROWS, WORDS, matrix_spec,
+                                  pack_fields, pack_matrix, row_block,
+                                  sign_bits)
 
 
-def _momentum_sign_pack_kernel(g_ref, m_ref, m_out_ref, p_out_ref, *,
+def _momentum_sign_pack_kernel(g_ref, m_ref, w_ref, m_out_ref, p_out_ref, *,
                                beta: float):
     g = g_ref[...]
     m = m_ref[...]
     m_new = beta * m + (1.0 - beta) * g.astype(m.dtype)
     m_out_ref[...] = m_new
-    bits = (m_new >= 0).astype(jnp.uint32)
-    bits = bits.reshape(m_new.shape[0], m_new.shape[1] // PACK, PACK)
-    acc = jnp.zeros(bits.shape[:2], jnp.uint32)
-    for j in range(PACK):
-        acc = acc | (bits[:, :, j] << jnp.uint32(j))
-    p_out_ref[...] = acc
+    p_out_ref[...] = pack_fields(sign_bits(m_new), w_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("beta", "interpret"))
@@ -48,18 +43,19 @@ def momentum_sign_pack(g: jax.Array, m: jax.Array, beta: float, *,
     """g/m (rows, 32*w) -> (m_new (rows, 32*w), packed (rows, w))."""
     rows, n = g.shape
     w = n // PACK
-    grid = (rows // ROWS, w // WORDS)
+    r = row_block(rows, WORDS * PACK * (g.dtype.itemsize + m.dtype.itemsize))
     return pl.pallas_call(
         functools.partial(_momentum_sign_pack_kernel, beta=beta),
-        grid=grid,
-        in_specs=[pl.BlockSpec((ROWS, WORDS * PACK), lambda i, j: (i, j)),
-                  pl.BlockSpec((ROWS, WORDS * PACK), lambda i, j: (i, j))],
-        out_specs=[pl.BlockSpec((ROWS, WORDS * PACK), lambda i, j: (i, j)),
-                   pl.BlockSpec((ROWS, WORDS), lambda i, j: (i, j))],
+        grid=(rows // r, w // WORDS),
+        in_specs=[pl.BlockSpec((r, WORDS * PACK), lambda i, j: (i, j)),
+                  pl.BlockSpec((r, WORDS * PACK), lambda i, j: (i, j)),
+                  matrix_spec(PACK)],
+        out_specs=[pl.BlockSpec((r, WORDS * PACK), lambda i, j: (i, j)),
+                   pl.BlockSpec((r, WORDS), lambda i, j: (i, j))],
         out_shape=[jax.ShapeDtypeStruct((rows, n), m.dtype),
                    jax.ShapeDtypeStruct((rows, w), jnp.uint32)],
         interpret=interpret,
-    )(g, m)
+    )(g, m, pack_matrix(PACK, 1))
 
 
 def _apply_vote_kernel(p_ref, v_ref, out_ref, *, eta: float,

@@ -7,8 +7,10 @@ bit positions, count set bits across the M voters (vectorised over the
 word/lane dim), compare against M/2, re-pack. No unpacking to float ever
 touches HBM — the whole vote is integer VPU work on VMEM tiles.
 
-Block shape: (M, 512) words per grid step (M is small — the vote runs over
-data-parallel replicas, 16..32 — so a whole voter column fits VMEM).
+Block shape: (M, R, 128) words -> (R, 128) per grid step, the (M, w)
+words viewed as (M, w/128, 128) so no lane row is ever reshaped in the
+kernel (M is small — the vote runs over data-parallel replicas, 4..32 —
+so a whole voter column fits VMEM).
 """
 from __future__ import annotations
 
@@ -18,16 +20,18 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-PACK = 32
-WBLOCK = 512
+from repro.kernels.bitpack import PACK, WORDS, row_block
+
+#: words per padding unit: 8 rows of 128 lanes
+WBLOCK = 8 * WORDS
 
 
 def _majority_kernel(p_ref, out_ref, *, m_voters: int):
-    p = p_ref[...]                                    # (M, WBLOCK) uint32
-    acc = jnp.zeros((p.shape[1],), jnp.uint32)
+    p = p_ref[...]                                    # (M, R, WORDS) uint32
+    acc = jnp.zeros(p.shape[1:], jnp.uint32)
     for j in range(PACK):                             # bit-sliced count
-        bits = (p >> jnp.uint32(j)) & jnp.uint32(1)   # (M, W)
-        cnt = jnp.sum(bits.astype(jnp.int32), axis=0)  # (W,)
+        bits = (p >> jnp.uint32(j)) & jnp.uint32(1)
+        cnt = jnp.sum(bits.astype(jnp.int32), axis=0)  # (R, WORDS)
         maj = (2 * cnt >= m_voters).astype(jnp.uint32)
         acc = acc | (maj << jnp.uint32(j))
     out_ref[...] = acc
@@ -36,14 +40,16 @@ def _majority_kernel(p_ref, out_ref, *, m_voters: int):
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def majority_packed(packed: jax.Array, *, interpret: bool = False
                     ) -> jax.Array:
-    """packed (M, w) uint32, w % 512 == 0 -> (w,) packed majority."""
+    """packed (M, w) uint32, w % 1024 == 0 -> (w,) packed majority."""
     m, w = packed.shape
-    grid = (w // WBLOCK,)
-    return pl.pallas_call(
+    rows = w // WORDS
+    r = row_block(rows, m * WORDS * 4)
+    out = pl.pallas_call(
         functools.partial(_majority_kernel, m_voters=m),
-        grid=grid,
-        in_specs=[pl.BlockSpec((m, WBLOCK), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((WBLOCK,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((w,), jnp.uint32),
+        grid=(rows // r,),
+        in_specs=[pl.BlockSpec((m, r, WORDS), lambda i: (0, i, 0))],
+        out_specs=pl.BlockSpec((r, WORDS), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, WORDS), jnp.uint32),
         interpret=interpret,
-    )(packed)
+    )(packed.reshape(m, rows, WORDS))
+    return out.reshape(-1)

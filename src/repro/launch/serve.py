@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_config, reduced_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.obs import recorder as obs
 from repro.train.serve_step import make_cache_rehome, make_decode_step
@@ -119,6 +120,7 @@ def main() -> None:
                     help="--engine: offered load in requests/tick")
     obs.add_trace_arg(ap)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -1,18 +1,26 @@
-"""Training launcher with checkpoint/restart, watchdog and elastic rescale.
+"""Single-device training launcher with checkpoint/restart and a watchdog.
 
-CPU-scale entry point (full-scale runs use the same code path under a real
-TPU mesh — the mesh simply comes from jax.devices()):
+Trains one replica (M=1: the vote degenerates to sign) on the default
+device, starting from the arch's preset (``configs.presets``: optimizer
+mode, momentum dtype, remat, microbatches); a flag overrides the preset
+only when it is given. ``--reduced`` runs a smoke-scale config of the
+same family, e.g. on the CPU:
 
-  PYTHONPATH=src python -m repro.launch.train --arch zamba2-1.2b \\
-      --reduced --steps 200 --batch 8 --seq 128 --ckpt-dir /tmp/ckpt
+  PYTHONPATH=src JAX_PLATFORMS=cpu python -m repro.launch.train \\
+      --arch zamba2-1.2b --reduced --steps 200 --batch 8 --seq 128 \\
+      --ckpt-dir /tmp/ckpt
 
-Fault-tolerance loop: every step runs under a Watchdog; on timeout or
-crash the launcher restores the latest checkpoint (possibly onto a smaller
-survivor mesh via distributed.fault_tolerance.plan_rescale) and resumes.
+Without ``--reduced`` it trains the published widths on one TPU chip.
+Multi-chip meshes are built by ``launch.mesh``; ``chip_smoke.py
+--four-chip`` drives the mesh step.
+
+Every step runs under a Watchdog, which raises on timeout; a rerun with
+the same ``--ckpt-dir`` resumes from the latest checkpoint.
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 from typing import Optional
 
@@ -21,28 +29,57 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.checkpoint import AsyncCheckpointer, latest_step_dir, restore
-from repro.configs.base import (SHAPES, OptimizerConfig, ShapeCell,
-                                TrainConfig, get_config, reduced_config)
-from repro.core import attacks
+from repro.configs.base import (MomentumMode, ShapeCell, get_config,
+                                reduced_config)
 from repro.configs.presets import default_train_config
+from repro.core import attacks
 from repro.data.pipeline import SyntheticLMPipeline
 from repro.distributed.fault_tolerance import Watchdog
-from repro.models import model as M
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import recorder as obs
 from repro.train import train_step as TS
 
 
+#: optimizer kinds ``--opt`` accepts (``OptimizerConfig.kind``)
+OPT_KINDS = ("signum_vote", "signsgd_vote", "sgd", "sgdm", "adam")
+
+
 def build(arch: str, *, reduced: bool, batch: int, seq: int,
-          opt_kind: str, lr: float, momentum: float, microbatches: int,
-          byz_mode: str, byz_n: int):
+          opt_kind: Optional[str] = None, lr: Optional[float] = None,
+          momentum: Optional[float] = None,
+          microbatches: Optional[int] = None, byz_mode: str = "none",
+          byz_n: int = 0):
+    """(ModelConfig, TrainConfig) for `arch`: the preset of
+    ``default_train_config``, with each of `opt_kind`, `lr`, `momentum`
+    and `microbatches` that is not None overriding its field. A sign
+    kind other than the preset's must keep the preset's momentum mode:
+    Mode B archs (global momentum) train ``signsgd_vote`` only."""
+    if opt_kind is not None and opt_kind not in OPT_KINDS:
+        raise ValueError(f"unknown optimizer kind {opt_kind!r}; "
+                         f"one of {OPT_KINDS}")
     cfg = get_config(arch)
     if reduced:
         cfg = reduced_config(cfg)
-    opt = OptimizerConfig(kind=opt_kind, learning_rate=lr, momentum=momentum)
-    tcfg = TrainConfig(
-        global_batch=batch, seq_len=seq, microbatches=microbatches,
-        optimizer=opt,
+    tcfg = default_train_config(
+        arch, ShapeCell("cli", seq, batch, "train"),
+        kind=opt_kind or "signum_vote",
         byzantine=attacks.build_config(byz_mode, byz_n))
+    opt_over = {k: v for k, v in (("kind", opt_kind),
+                                  ("learning_rate", lr),
+                                  ("momentum", momentum)) if v is not None}
+    if (opt_kind is not None and opt_kind != tcfg.optimizer.kind
+            and tcfg.optimizer.momentum_mode != MomentumMode.PER_WORKER):
+        raise ValueError(
+            f"{arch} trains Mode B ({tcfg.optimizer.kind}, global "
+            f"momentum); --opt {opt_kind} needs per-worker momentum")
+    tcfg = dataclasses.replace(
+        tcfg, optimizer=dataclasses.replace(tcfg.optimizer, **opt_over))
+    if microbatches is not None:
+        tcfg = dataclasses.replace(tcfg, microbatches=microbatches)
+    if batch % tcfg.microbatches:
+        raise ValueError(
+            f"--batch {batch} does not split into {tcfg.microbatches} "
+            f"microbatches ({arch}'s preset); pass --microbatches")
     return cfg, tcfg
 
 
@@ -54,10 +91,15 @@ def main() -> None:
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=128)
-    ap.add_argument("--opt", default="signum_vote")
-    ap.add_argument("--lr", type=float, default=1e-3)
-    ap.add_argument("--momentum", type=float, default=0.9)
-    ap.add_argument("--microbatches", type=int, default=1)
+    ap.add_argument("--opt", default=None, choices=OPT_KINDS,
+                    help="optimizer kind (default: the arch preset's)")
+    ap.add_argument("--lr", type=float, default=None,
+                    help="learning rate (default: the arch preset's)")
+    ap.add_argument("--momentum", type=float, default=None,
+                    help="momentum beta (default: the arch preset's)")
+    ap.add_argument("--microbatches", type=int, default=None,
+                    help="gradient-accumulation steps (default: the arch "
+                         "preset's)")
     ap.add_argument("--byzantine", default="none")
     ap.add_argument("--adversaries", type=int, default=0)
     ap.add_argument("--ckpt-dir", default=None)
@@ -73,6 +115,7 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     obs.add_trace_arg(ap)
     args = ap.parse_args()
+    enable_compile_cache()
     trace_rec = obs.activate_trace(args)
     rec = obs.get_recorder()
 
@@ -113,7 +156,7 @@ def main() -> None:
                 loss = float(metrics["loss"])
         if rec.enabled:
             rec.step(kind_detail="train", step=step, loss=loss,
-                     arch=args.arch, opt=args.opt,
+                     arch=args.arch, opt=tcfg.optimizer.kind,
                      phase_s={"step": sp.dur_s})
         if wd.fired:
             raise TimeoutError(f"step {step} exceeded {args.watchdog_s}s")

@@ -268,9 +268,8 @@ class ScenarioRunner:
     (real shard_map collectives; every segment's voter count must fit the
     local device count). `mesh_style` picks the mesh layout for the mesh
     backend: "data_model" = an (M, 1) ('data', 'model') mesh, manual over
-    'data' only — the trainer's partial-auto configuration, which on
-    legacy JAX exercises the compat emulation layer; "data_only" = a
-    fully-manual (M,) mesh using the native collective lowerings.
+    'data' only — the trainer's partial-auto configuration; "data_only"
+    = a fully-manual (M,) mesh.
     """
 
     def __init__(self, spec: ScenarioSpec, backend: str = "virtual",

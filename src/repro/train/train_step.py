@@ -225,20 +225,8 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
                     lambda a, b: a + b.astype(a.dtype), carry, g)
                 return carry, (loss, met)
 
-            zeros = jax.tree.map(
-                lambda p: compat.zeros_like_traced(p, acc_dt), params)
-            if compat.SCAN_OVER_MANUAL_XS_SAFE or mesh is None:
-                grads, (losses, mets) = jax.lax.scan(acc_body, zeros, micro)
-            else:
-                # legacy partial-auto: scan over batch-derived xs aborts the
-                # SPMD partitioner — unroll (identical accumulation)
-                grads, acc = zeros, []
-                for i in range(tcfg.microbatches):
-                    grads, lm = acc_body(
-                        grads, jax.tree.map(lambda x: x[i], micro))
-                    acc.append(lm)
-                losses, mets = jax.tree.map(
-                    lambda *xs: jnp.stack(xs), *acc)
+            zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, acc_dt), params)
+            grads, (losses, mets) = jax.lax.scan(acc_body, zeros, micro)
             grads = jax.tree.map(lambda g: g / tcfg.microbatches, grads)
             loss = jnp.mean(losses)
             metrics = jax.tree.map(jnp.mean, mets)
@@ -269,7 +257,8 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
     # ------------------------------------------------------------------
     if mesh is None:
         return StepArtifacts(
-            step_fn=jax.jit(local_step), param_specs=specs,
+            step_fn=jax.jit(local_step, donate_argnums=(0, 1)),
+            param_specs=specs,
             param_shard_specs={k: P() for k in specs}, opt_specs=None,
             batch_spec=None, n_vote_replicas=1, vote_axes=(),
             fused_leaves=fused_leaves, vote_strategy=resolved,

@@ -4,8 +4,8 @@ test_harness8.py (so the main pytest session keeps 1 CPU device).
 On an 8-device host platform it validates the Scenario Lab's central
 claim — the virtual mesh IS the wire path:
 
-  1. compat shims on 8 devices: axis_index / all_gather partial-auto
-     emulations, straggler_mask_for, and apply_adversary (mesh) ==
+  1. compat on 8 devices: axis_index / all_gather in a partial-auto
+     region, straggler_mask_for, and apply_adversary (mesh) ==
      apply_adversary_stacked (virtual) for every stochastic mode;
   2. mesh backend == virtual backend, bit for bit (digest equality), for
      every strategy x adversary-mode x straggler x elastic composition,
@@ -117,7 +117,7 @@ def check_compat_shims_8dev():
                             axis_types=(AxisType.Auto,) * 2)
 
     def f(x):
-        idx = compat.axis_index("data", like=x)       # emulated on legacy
+        idx = compat.axis_index("data", like=x)
         g = compat.all_gather(x[0], "data", tiled=False)
         mask = ft.straggler_mask_for(("data",), 3, like=x)
         return (jnp.full((1,), idx, jnp.int32),

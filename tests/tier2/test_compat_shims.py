@@ -1,11 +1,11 @@
-"""Regression tests for the repro/compat.py emulation layer (satellite:
-"so the next JAX bump can't silently break it").
+"""Regression tests for repro/compat.py, the pass-through layer over the
+JAX mesh/shard_map API that every caller imports.
 
-The shims under test: ``shard_map`` kwarg mapping (axis_names/check_vma),
-``axis_index`` / ``all_gather`` partial-auto emulations (with the `like=`
-anchor), ``pad_trailing`` / ``zeros_like_traced``, ``set_mesh`` /
-``get_abstract_mesh`` context views, and ``make_mesh`` axis_types
-tolerance — all on the 1-device harness here; the 8-device half lives in
+The surface under test: ``shard_map`` kwargs (axis_names/check_vma,
+mesh from context), ``axis_index`` / ``all_gather`` inside a
+partial-manual region, ``pad_trailing``,
+``set_mesh`` / ``get_abstract_mesh``, and ``make_mesh`` with
+axis_types — all on the 1-device harness here; the 8-device half lives in
 ``tests/tier2/scenario_harness.py`` (XLA_FLAGS-forced device count, run
 by test_harness8.py).
 """
@@ -35,8 +35,8 @@ def _mesh1():
 
 
 def test_shard_map_kwargs_partial_manual():
-    """New-style kwargs (axis_names subset, check_vma) run on any JAX;
-    'model' stays auto."""
+    """kwargs (axis_names subset, check_vma) map through; 'model' stays
+    auto."""
     mesh = _mesh11()
 
     def f(x):
@@ -63,7 +63,7 @@ def test_shard_map_full_manual_defaults():
 
 
 def test_shard_map_mesh_from_context():
-    """mesh=None resolves from the set_mesh context (both API families)."""
+    """mesh=None resolves from the set_mesh context."""
     mesh = _mesh11()
     with compat.set_mesh(mesh):
         sh = compat.shard_map(lambda x: x * 2.0, in_specs=(P("data"),),
@@ -85,7 +85,7 @@ def test_get_abstract_mesh_views():
 
 
 # ---------------------------------------------------------------------------
-# collectives and index emulation (partial-auto region)
+# collectives and axis index (partial-auto region)
 # ---------------------------------------------------------------------------
 
 
@@ -125,18 +125,14 @@ def test_pad_trailing_and_zeros_like_inside_region():
     mesh = _mesh11()
 
     def f(x):
-        p = compat.pad_trailing(x[0], 3)
-        z = compat.zeros_like_traced(x[0], jnp.int8)
-        return p[None], z[None]
+        return compat.pad_trailing(x[0], 3)[None]
 
     sh = compat.shard_map(f, mesh=mesh, in_specs=(P("data"),),
-                          out_specs=(P("data"), P("data")),
-                          axis_names={"data"}, check_vma=False)
-    p, z = jax.jit(sh)(jnp.ones((1, 5)))
+                          out_specs=P("data"), axis_names={"data"},
+                          check_vma=False)
+    p = jax.jit(sh)(jnp.ones((1, 5)))
     np.testing.assert_array_equal(
         np.asarray(p)[0], np.concatenate([np.ones(5), np.zeros(3)]))
-    assert np.asarray(z).dtype == np.int8
-    np.testing.assert_array_equal(np.asarray(z)[0], np.zeros(5))
 
 
 def test_pad_trailing_noop_and_plain():
@@ -147,7 +143,7 @@ def test_pad_trailing_noop_and_plain():
 
 
 def test_axis_size_inside_and_make_mesh_tolerance():
-    # make_mesh must accept axis_types on every JAX (dropping if needed)
+    # make_mesh passes axis_types through
     mesh = compat.make_mesh((1,), ("data",),
                             axis_types=(AxisType.Auto,))
     assert tuple(mesh.axis_names) == ("data",)
