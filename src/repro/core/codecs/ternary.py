@@ -32,8 +32,10 @@ from repro import compat
 from repro.configs.base import VoteStrategy
 from repro.core import sign_compress as sc
 from repro.core.codecs.base import GradientCodec
+from repro.obs.scopes import scope_stages
 
 
+@scope_stages
 class TernaryWire:
     """The 2-bit packed transport, shaped like a VoteStrategyImpl's four
     stages so the mesh engine composes them over collectives and the

@@ -32,7 +32,6 @@ from repro.core import sign_compress as sc
 from repro.core import vote_api as va
 from repro.core import vote_plan as vp
 from repro.core.majority_vote import tree_mean
-from repro.obs import recorder as obs
 
 
 @dataclasses.dataclass(frozen=True)
@@ -146,17 +145,13 @@ def make_sign_optimizer(cfg: OptimizerConfig, axes: Sequence[str],
     def encode(tree, err):
         # codec encode: fold each EF leaf's residual into the vote input
         # (identity for residual-free leaves/codecs)
-        with obs.get_recorder().span("codec.encode", codec=codec.name,
-                                     n_leaves=len(tree)):
-            return {k: _leaf_codec(k).encode_leaf(v, err.get(k))
-                    for k, v in tree.items()}
+        return {k: _leaf_codec(k).encode_leaf(v, err.get(k))
+                for k, v in tree.items()}
 
     def feedback(encoded, votes, err):
         # codec feedback: residual vs the APPLIED vote, EF leaves only
-        with obs.get_recorder().span("codec.feedback", codec=codec.name,
-                                     n_leaves=len(err)):
-            return {k: _leaf_codec(k).feedback_leaf(encoded[k], votes[k], e)
-                    for k, e in err.items()}
+        return {k: _leaf_codec(k).feedback_leaf(encoded[k], votes[k], e)
+                for k, e in err.items()}
 
     backend = va.MeshBackend(axes=tuple(axes))
 
@@ -183,9 +178,10 @@ def make_sign_optimizer(cfg: OptimizerConfig, axes: Sequence[str],
         if mode == MomentumMode.PER_WORKER:
             # --- Algorithm 1 verbatim ---
             if beta > 0:
-                v = jax.tree.map(
-                    lambda m, g: beta * m + (1 - beta) * g.astype(mom_dtype),
-                    state["momentum"], grads)
+                with jax.named_scope("sign_momentum"):
+                    v = jax.tree.map(
+                        lambda m, g: beta * m + (1 - beta)
+                        * g.astype(mom_dtype), state["momentum"], grads)
                 state = {**state, "momentum": v}
             else:
                 v = grads
@@ -214,11 +210,12 @@ def make_sign_optimizer(cfg: OptimizerConfig, axes: Sequence[str],
                 diag["vote_agreement"] = jnp.float32(jnp.nan)
                 diag["vote_margin"] = jnp.float32(jnp.nan)
             if beta > 0:
-                u = jax.tree.map(
-                    lambda m, vt: beta * m + (1 - beta) * vt.astype(mom_dtype),
-                    state["momentum"], votes)
+                with jax.named_scope("sign_momentum"):
+                    u = jax.tree.map(
+                        lambda m, vt: beta * m + (1 - beta)
+                        * vt.astype(mom_dtype), state["momentum"], votes)
+                    votes = jax.tree.map(lambda x: jnp.sign(x), u)
                 state = {**state, "momentum": u}
-                votes = jax.tree.map(lambda x: jnp.sign(x), u)
         if cfg.delayed_vote:
             # apply the PREVIOUS step's majority; bank this step's fresh
             # decision for t+1. EF feedback and the diagnostics above
@@ -237,7 +234,8 @@ def make_sign_optimizer(cfg: OptimizerConfig, axes: Sequence[str],
             upd = vt.astype(jnp.float32) + cfg.weight_decay * p32
             return (p32 - eta * upd).astype(p.dtype)
 
-        new_params = jax.tree.map(apply, params, applied)
+        with jax.named_scope("sign_update"):
+            new_params = jax.tree.map(apply, params, applied)
         state = {**state, "count": state["count"] + 1}
         return new_params, state, diag
 

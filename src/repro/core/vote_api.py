@@ -44,6 +44,7 @@ This module is also the single home of the pack-width helpers
 from __future__ import annotations
 
 import abc
+import contextlib
 import dataclasses
 import functools
 import warnings
@@ -679,7 +680,8 @@ def _leaf_execute(values: jax.Array, axes: Tuple[str, ...],
                                               salt=salt, obs=obs)
         vote, new_state = _plan_walk(plan, signs, axes, server_state,
                                      overlap)
-        return vote.astype(values.dtype), new_state
+        with _decode_scope(axes):
+            return vote.astype(values.dtype), new_state
     shape = values.shape
     s = sc.sign_ternary(values if values.ndim else values.reshape(1))
     if byz is not None and axes:
@@ -687,7 +689,17 @@ def _leaf_execute(values: jax.Array, axes: Tuple[str, ...],
                                       obs=obs)
     vote, new_state = _wire_vote_signs(s, axes, strategy, codec_name,
                                        server_state)
-    return vote.reshape(shape).astype(values.dtype), new_state
+    with _decode_scope(axes):
+        return vote.reshape(shape).astype(values.dtype), new_state
+
+
+def _decode_scope(axes: Tuple[str, ...]):
+    """The cast of a voted decision back to the payload dtype ends the
+    decode: XLA fuses the unpack into it and names the fusion after it,
+    so it runs under the ``vote_unpack`` device scope (DESIGN.md §13).
+    Without vote axes nothing was decoded."""
+    return jax.named_scope("vote_unpack") if axes else \
+        contextlib.nullcontext()
 
 
 # ---- tree execution (absorbed VoteEngine.vote_tree_codec /

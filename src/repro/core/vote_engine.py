@@ -50,7 +50,7 @@ from repro import compat
 from repro.configs.base import ByzantineConfig, VoteStrategy
 from repro.core import sign_compress as sc
 from repro.distributed import comm_model
-from repro.obs import recorder as obs
+from repro.obs.scopes import scope_stages
 
 
 # ---------------------------------------------------------------------------
@@ -82,15 +82,15 @@ from repro.core.vote_api import pad_last as _pad_last  # noqa: E402
 # strategy interface
 # ---------------------------------------------------------------------------
 
-
 class VoteStrategyImpl(abc.ABC):
     """One wire protocol for the majority vote.
 
     ``vote`` composes the four pipeline stages over the vote axes; the
     accounting methods price the exchange stage for the cost model and the
-    benchmarks. Inputs to ``vote`` are replica-local int8 sign tensors
-    (ternary ok); outputs are int8 majorities with this strategy's tie
-    convention.
+    benchmarks. Each subclass's own stage methods run under their
+    ``vote_<stage>`` device scopes (``obs.scopes.scope_stages``). Inputs
+    to ``vote`` are replica-local int8 sign tensors (ternary ok); outputs
+    are int8 majorities with this strategy's tie convention.
     """
 
     kind: VoteStrategy
@@ -98,6 +98,10 @@ class VoteStrategyImpl(abc.ABC):
     wire_bits_per_param: float
     #: tie convention of the decoded majority ("zero" or "plus_one")
     ties: str
+
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        scope_stages(cls)
 
     # ---- pipeline stages ----
 
@@ -118,30 +122,13 @@ class VoteStrategyImpl(abc.ABC):
         """Decode the decision to (..., n) ±1/0 signs in `dtype`."""
 
     def vote(self, signs: jax.Array, axes: Sequence[str]) -> jax.Array:
-        """signs int8 (..., n) -> int8 majority (..., n) over `axes`.
-
-        With a recorder active, each stage is wrapped in a host-side
-        span (``stage.pack`` .. ``stage.unpack``, DESIGN.md §13); under
-        ``jit`` the spans measure trace time and insert NO ops, so the
-        compiled program — and the golden digest — is bit-identical
-        with tracing on."""
+        """signs int8 (..., n) -> int8 majority (..., n) over `axes`."""
         m = num_voters(axes)
         n = signs.shape[-1]
-        rec = obs.get_recorder()
-        if not rec.enabled:
-            wire = self.pack(signs, m)
-            arrived = self.exchange(wire, axes)
-            decision = self.tally(arrived, m)
-            return self.unpack(decision, n, jnp.int8)
-        kind = self.kind.value
-        with rec.span("stage.pack", strategy=kind, n=n):
-            wire = self.pack(signs, m)
-        with rec.span("stage.exchange", strategy=kind, n=n):
-            arrived = self.exchange(wire, axes)
-        with rec.span("stage.tally", strategy=kind, n=n):
-            decision = self.tally(arrived, m)
-        with rec.span("stage.unpack", strategy=kind, n=n):
-            return self.unpack(decision, n, jnp.int8)
+        wire = self.pack(signs, m)
+        arrived = self.exchange(wire, axes)
+        decision = self.tally(arrived, m)
+        return self.unpack(decision, n, jnp.int8)
 
     # ---- accounting (per-chip bytes; ring collective terms) ----
 

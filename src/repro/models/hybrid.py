@@ -60,12 +60,16 @@ def _mamba_segment_scan(lp: Dict[str, jax.Array], h: jax.Array, cfg,
 
 def _shared_attn_block(sp: Dict[str, jax.Array], h: jax.Array, cfg
                        ) -> Tuple[jax.Array, Tuple[jax.Array, jax.Array]]:
-    x = L.rms_norm(h, sp["norm1_scale"], cfg.norm_eps)
-    attn_out, kv = L.self_attention_block(sp, "attn", x, cfg, causal=True)
-    h = h + attn_out
-    x = L.rms_norm(h, sp["norm2_scale"], cfg.norm_eps)
-    h = h + L.swiglu_mlp(sp, "mlp", x)
-    return h, kv
+    """The shared attention + SwiGLU block with its norms, under the
+    ``shared_block`` device scope."""
+    with jax.named_scope("shared_block"):
+        x = L.rms_norm(h, sp["norm1_scale"], cfg.norm_eps)
+        attn_out, kv = L.self_attention_block(sp, "attn", x, cfg,
+                                              causal=True)
+        h = h + attn_out
+        x = L.rms_norm(h, sp["norm2_scale"], cfg.norm_eps)
+        h = h + L.swiglu_mlp(sp, "mlp", x)
+        return h, kv
 
 
 def hybrid_forward(p: Dict[str, jax.Array], h: jax.Array, cfg,

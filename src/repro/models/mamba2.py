@@ -50,7 +50,16 @@ def _project(p, prefix: str, x: jax.Array) -> Tuple[jax.Array, ...]:
 
 def mamba2_forward(p: Dict[str, jax.Array], x_in: jax.Array, cfg,
                    prefix: str = "mamba") -> jax.Array:
-    """One Mamba2 mixer (no residual). x_in (B,S,d) -> (B,S,d)."""
+    """One Mamba2 mixer (no residual). x_in (B,S,d) -> (B,S,d).
+
+    Device scopes: ``mixer`` over the whole body, ``ssd`` over the
+    chunked scan (DESIGN.md §13)."""
+    with jax.named_scope("mixer"):
+        return _mixer(p, x_in, cfg, prefix)
+
+
+def _mixer(p: Dict[str, jax.Array], x_in: jax.Array, cfg,
+           prefix: str) -> jax.Array:
     s = cfg.ssm
     B, S, d = x_in.shape
     di, N, nh, P = s.d_inner(d), s.state_dim, s.n_heads(d), s.head_dim
@@ -67,11 +76,26 @@ def mamba2_forward(p: Dict[str, jax.Array], x_in: jax.Array, cfg,
 
     dt = jax.nn.softplus(dt.astype(jnp.float32) + p[f"{prefix}_dt_bias"])
     A = -jnp.exp(p[f"{prefix}_A_log"].astype(jnp.float32))     # (nh,)
+    with jax.named_scope("ssd"):
+        Y = _ssd(p, prefix, x, B_, C_, dt, A, (nc, cs, nh, P), x_in.dtype)
+
+    # gated RMSNorm then output projection
+    Y = Y * jax.nn.silu(z).astype(x_in.dtype)
+    Y = rms_norm(Y, p[f"{prefix}_norm_scale"], cfg.norm_eps)
+    return Y @ p[f"{prefix}_out_proj"]
+
+
+def _ssd(p, prefix: str, x, B_, C_, dt, A, dims, cdt) -> jax.Array:
+    """The chunked scan: x (B,S,d_inner), B_/C_ (B,S,N), dt (B,S,nh) f32,
+    A (nh,), `dims` (chunks, chunk size, heads, head dim) -> Y
+    (B,S,d_inner) in `cdt`, the D skip included."""
+    B, S, di = x.shape
+    N = B_.shape[-1]
+    nc, cs, nh, P = dims
 
     # Big (B,S,d_inner)-sized tensors stay bf16 (activation dtype); decay /
     # cumsum / state-recurrence math stays fp32 (small: (b,s,h) and
     # (b,nc,h,p,n)). This halves the dominant SSD temporaries.
-    cdt = x_in.dtype
     xh = x.reshape(B, nc, cs, nh, P).astype(cdt)
     xh = shard(xh, BATCH, None, None, "model", None)
     Bc = B_.reshape(B, nc, cs, N).astype(cdt)
@@ -116,12 +140,7 @@ def mamba2_forward(p: Dict[str, jax.Array], x_in: jax.Array, cfg,
 
     Y = (Y_diag + Y_off).reshape(B, S, nh, P)
     Y = Y + xh.reshape(B, S, nh, P) * p[f"{prefix}_D"].astype(cdt)[:, None]
-    Y = Y.reshape(B, S, di)
-
-    # gated RMSNorm then output projection
-    Y = Y * jax.nn.silu(z).astype(cdt)
-    Y = rms_norm(Y, p[f"{prefix}_norm_scale"], cfg.norm_eps)
-    return Y @ p[f"{prefix}_out_proj"]
+    return Y.reshape(B, S, di)
 
 
 # ---------------------------------------------------------------------------

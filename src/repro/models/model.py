@@ -78,14 +78,16 @@ def _vlm_split(cell_seq: int) -> Tuple[int, int]:
 
 
 def _embed_input(cfg: ModelConfig, params, batch) -> jax.Array:
-    """Build the (B, S, d) input stream for decoder-style archs."""
-    tok = L.embed_tokens(params["embed.table"], batch["tokens"])
-    if cfg.family == ArchFamily.VLM and "patch_embeds" in batch:
-        h = jnp.concatenate(
-            [batch["patch_embeds"].astype(tok.dtype), tok], axis=1)
-    else:
-        h = tok
-    return shard(h, BATCH, None, None)
+    """Build the (B, S, d) input stream for decoder-style archs (the
+    ``embed`` device scope)."""
+    with jax.named_scope("embed"):
+        tok = L.embed_tokens(params["embed.table"], batch["tokens"])
+        if cfg.family == ArchFamily.VLM and "patch_embeds" in batch:
+            h = jnp.concatenate(
+                [batch["patch_embeds"].astype(tok.dtype), tok], axis=1)
+        else:
+            h = tok
+        return shard(h, BATCH, None, None)
 
 
 # ---------------------------------------------------------------------------
@@ -134,10 +136,11 @@ def forward_logits(cfg: ModelConfig, params, batch, hook=None,
         h = _embed_input(cfg, params, batch)
         h, aux = transformer.decoder_stack(params, h, cfg, hook=hook,
                                            remat=remat)
-    h = L.rms_norm(h, params["final_norm.scale"], cfg.norm_eps)
-    table = params.get("unembed.table", params["embed.table"])
-    logits = jnp.einsum("bsd,vd->bsv", h, table)
-    return shard(logits, BATCH, None, "model"), aux
+    with jax.named_scope("lm_head"):
+        h = L.rms_norm(h, params["final_norm.scale"], cfg.norm_eps)
+        table = params.get("unembed.table", params["embed.table"])
+        logits = jnp.einsum("bsd,vd->bsv", h, table)
+        return shard(logits, BATCH, None, "model"), aux
 
 
 def loss_fn(cfg: ModelConfig, params, batch, hook=None, remat: str = "none"
@@ -147,7 +150,8 @@ def loss_fn(cfg: ModelConfig, params, batch, hook=None, remat: str = "none"
     if cfg.family == ArchFamily.VLM and "patch_embeds" in batch:
         # loss only over the text segment (last `len(tokens)` positions)
         logits = logits[:, -tokens.shape[1]:]
-    ce = L.cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
+    with jax.named_scope("lm_head"):
+        ce = L.cross_entropy_loss(logits[:, :-1], tokens[:, 1:])
     loss = ce + aux
     return loss, {"ce": ce, "aux": aux}
 

@@ -12,13 +12,14 @@ from repro.obs.recorder import (COUNTERS, CounterRegistry, Recorder,
                                 activate_trace, add_trace_arg,
                                 emit_bench_json, finish_trace,
                                 get_recorder, install_compile_watch,
-                                read_trace, recording, set_recorder,
-                                warn_deprecated)
+                                jit_counters_at, read_trace, recording,
+                                set_recorder, warn_deprecated)
 
 __all__ = [
     "COUNTERS", "CounterRegistry", "Recorder", "SCHEMA_VERSION",
     "TraceRecorder", "activate_trace", "add_trace_arg",
     "emit_bench_json", "finish_trace", "get_recorder",
-    "install_compile_watch", "read_trace", "recording", "set_recorder",
+    "install_compile_watch", "jit_counters_at", "read_trace",
+    "recording", "set_recorder",
     "warn_deprecated",
 ]

@@ -14,7 +14,10 @@
   ops into a jitted graph; a span around code under ``jax.jit``
   measures *trace/dispatch* time, which is exactly the host-side cost
   the schedule walk pays per bucket — the rows say so via the
-  ``host_side`` meta field.
+  ``host_side`` meta field. A live span also enters a
+  ``jax.profiler.TraceAnnotation`` of its name, so it lands in any
+  profiler trace beside the device ops. Device time is named by device
+  scopes instead (``obs/scopes.py``).
 * **Step records** — one structured row per training/scenario step
   unifying the ``WireReport`` and ``StepTrace`` fields (resolved
   strategy, payload bytes, compression vs f32, margin, flip-vs-oracle,
@@ -33,11 +36,13 @@ ScenarioRunner loop, `VoteBackend.execute` outside jit) run eagerly.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import json
+import threading
 import time
 import warnings
-from typing import Any, Dict, IO, Iterator, List, Optional
+from typing import Any, Deque, Dict, IO, Iterator, List, Optional, Tuple
 
 #: bump on any breaking change to the JSONL row shapes below
 SCHEMA_VERSION = 1
@@ -155,10 +160,11 @@ class Recorder:
 
 class _Span:
     """A live span: ``perf_counter`` on enter/exit, row written on exit
-    with nesting depth + parent seq from the recorder's span stack."""
+    with nesting depth + parent seq from the recorder's span stack; the
+    same interval is a ``jax.profiler.TraceAnnotation`` of its name."""
 
     __slots__ = ("_rec", "name", "attrs", "seq", "depth", "parent",
-                 "_t0", "dur_s")
+                 "_t0", "dur_s", "_ann")
 
     def __init__(self, rec: "TraceRecorder", name: str,
                  attrs: Dict[str, Any]):
@@ -170,21 +176,26 @@ class _Span:
         self.parent = -1
         self._t0 = 0.0
         self.dur_s = 0.0
+        self._ann = None
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
 
     def __enter__(self) -> "_Span":
+        from jax.profiler import TraceAnnotation
         rec = self._rec
         self.seq = rec._next_seq()
         self.depth = len(rec._stack)
         self.parent = rec._stack[-1].seq if rec._stack else -1
         rec._stack.append(self)
+        self._ann = TraceAnnotation(self.name)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> bool:
         t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
         self.dur_s = t1 - self._t0
         rec = self._rec
         if rec._stack and rec._stack[-1] is self:
@@ -344,38 +355,94 @@ def recording(rec: Recorder) -> Iterator[Recorder]:
 
 
 # ---------------------------------------------------------------------------
-# compile watch (jit recompile accounting)
+# compile watch (set-up phase accounting)
 # ---------------------------------------------------------------------------
 
+#: JAX's phase events -> the counter of nanoseconds each adds to
+JIT_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace_ns",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower_ns",
+    # a persistent-cache load runs inside this event too
+    "/jax/core/compile/backend_compile_duration": "jit.compile_ns",
+}
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+#: (perf_counter at the event's end, counter, amount) for each increment
+#: of a ``jit.*`` counter, oldest first, so a caller can split them by
+#: its own phases after the fact (:func:`jit_counters_at`)
+JIT_LOG: Deque[Tuple[float, str, int]] = collections.deque(maxlen=1 << 16)
+
 _COMPILE_WATCH_ON = False
+_OPEN = threading.local()      # phase events open on this thread
+
+
+def _jit_inc(name: str, amount: int) -> None:
+    COUNTERS.inc(name, amount)
+    JIT_LOG.append((time.perf_counter(), name, amount))
+
+
+def _on_phase_start(event: str, value, **kw) -> None:
+    if event in JIT_PHASES:
+        _OPEN.n = getattr(_OPEN, "n", 0) + 1
+
+
+def _on_phase_end(event: str, duration: float, **kw) -> None:
+    name = JIT_PHASES.get(event)
+    if name is None:
+        return
+    _OPEN.n = max(getattr(_OPEN, "n", 0) - 1, 0)
+    if name == "jit.compile_ns":
+        _jit_inc("jit.compiles", 1)
+    # a phase nested in another (a jitted helper traced inside the step,
+    # an eager op compiled while tracing) is already inside the outer
+    # one's time: only outermost phases add nanoseconds
+    if _OPEN.n:
+        return
+    _jit_inc(name, int(duration * 1e9))
+    rec = get_recorder()
+    if rec.enabled:
+        rec.event(name[:-3], event=event, dur_s=duration)
+
+
+def _on_event(event: str, **kw) -> None:
+    if event == CACHE_HIT_EVENT:
+        _jit_inc("jit.cache_hits", 1)
 
 
 def install_compile_watch() -> bool:
-    """Count jit compilations into ``jit.compiles`` (+ exact nanoseconds
-    into ``jit.compile_ns``) and emit a ``jit.compile`` event on the
-    active recorder, via ``jax.monitoring``'s duration listeners.
-    Idempotent; returns False (and stays inert) if the installed jax
-    has no monitoring hooks — telemetry must degrade, not crash."""
+    """Count JAX's set-up phases by their exact event names into the
+    always-on counters: ``jit.trace_ns`` (tracing to a jaxpr),
+    ``jit.lower_ns`` (jaxpr to MLIR), ``jit.compile_ns`` and
+    ``jit.compiles`` (the backend compile, or its load from the
+    persistent cache), ``jit.cache_hits`` (persistent-cache hits). Each
+    increment is also logged in :data:`JIT_LOG`, and an active recorder
+    gets a ``jit.trace`` / ``jit.lower`` / ``jit.compile`` event. Costs
+    one Python call per phase event, none per step. Idempotent; returns
+    False (and stays inert) if the installed jax has no monitoring hooks
+    — telemetry must degrade, not crash."""
     global _COMPILE_WATCH_ON
     if _COMPILE_WATCH_ON:
         return True
     try:
         from jax import monitoring
-
-        def _on_duration(event: str, duration: float, **kw) -> None:
-            if "compile" not in event:
-                return
-            COUNTERS.inc("jit.compiles")
-            COUNTERS.inc("jit.compile_ns", int(duration * 1e9))
-            rec = get_recorder()
-            if rec.enabled:
-                rec.event("jit.compile", event=event, dur_s=duration)
-
-        monitoring.register_event_duration_secs_listener(_on_duration)
+        monitoring.register_scalar_listener(_on_phase_start)
+        monitoring.register_event_duration_secs_listener(_on_phase_end)
+        monitoring.register_event_listener(_on_event)
     except Exception:
         return False
     _COMPILE_WATCH_ON = True
     return True
+
+
+def jit_counters_at(t: float) -> Dict[str, int]:
+    """The ``jit.*`` counters' increments logged up to ``perf_counter``
+    time `t` (e.g. the end of a program's set-up)."""
+    out: Dict[str, int] = {}
+    for when, name, amount in JIT_LOG:
+        if when > t:
+            break
+        out[name] = out.get(name, 0) + amount
+    return out
 
 
 # ---------------------------------------------------------------------------

@@ -40,6 +40,7 @@ from repro.core.signum import build_optimizer
 from repro.core.vote_engine import resolve_strategy
 from repro.distributed import sharding as shd
 from repro.models import model as M
+from repro.obs import recorder as obs
 
 
 # ---------------------------------------------------------------------------
@@ -88,6 +89,19 @@ def _constrain_grads(grads: Dict[str, jax.Array], specs: Dict[str, P],
     return out
 
 
+def _per_worker(state: Dict[str, Any], fn: Callable) -> Dict[str, Any]:
+    """`fn` over each leaf of the per-worker momentum and error trees.
+    The unwrap and re-wrap are the momentum's layout, so they run under
+    ``sign_momentum`` like its update: XLA fuses the re-wrap into the
+    update and names the fusion after it."""
+    state = {**state}
+    with jax.named_scope("sign_momentum"):
+        for key in ("momentum", "error"):
+            if key in state:
+                state[key] = jax.tree.map(fn, state[key])
+    return state
+
+
 def _mesh_axis_sizes(mesh) -> Dict[str, int]:
     return dict(zip(mesh.axis_names, mesh.devices.shape))
 
@@ -118,6 +132,7 @@ class StepArtifacts:
 
 def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
                     mesh=None) -> StepArtifacts:
+    obs.install_compile_watch()     # set-up phase counters (jit.*)
     opt_cfg = tcfg.optimizer
     byz = tcfg.byzantine if tcfg.byzantine.mode != "none" else None
     is_sign = opt_cfg.kind in ("signum_vote", "signsgd_vote")
@@ -198,11 +213,7 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
     def local_step(params, opt_state, batch, step):
         # ---- unwrap per-worker momentum (leading vote axis, local = 1) ----
         if per_worker:
-            opt_state = {**opt_state}
-            for key in ("momentum", "error"):
-                if key in opt_state:
-                    opt_state[key] = jax.tree.map(lambda v: v[0],
-                                                  opt_state[key])
+            opt_state = _per_worker(opt_state, lambda v: v[0])
         # ---- local gradients (manual over vote axes => no auto psum) ----
         if tcfg.microbatches > 1:
             # Sign optimizers accumulate in bf16: only the sign of the sum
@@ -221,13 +232,17 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
             def acc_body(carry, mb):
                 (loss, met), g = jax.value_and_grad(
                     loss_of, has_aux=True)(params, mb)
-                carry = jax.tree.map(
-                    lambda a, b: a + b.astype(a.dtype), carry, g)
+                with jax.named_scope("grad_accum"):
+                    carry = jax.tree.map(
+                        lambda a, b: a + b.astype(a.dtype), carry, g)
                 return carry, (loss, met)
 
-            zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, acc_dt), params)
+            with jax.named_scope("grad_accum"):
+                zeros = jax.tree.map(lambda p: jnp.zeros(p.shape, acc_dt),
+                                     params)
             grads, (losses, mets) = jax.lax.scan(acc_body, zeros, micro)
-            grads = jax.tree.map(lambda g: g / tcfg.microbatches, grads)
+            with jax.named_scope("grad_accum"):
+                grads = jax.tree.map(lambda g: g / tcfg.microbatches, grads)
             loss = jnp.mean(losses)
             metrics = jax.tree.map(jnp.mean, mets)
         else:
@@ -241,11 +256,7 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig,
                                                  step)
         # ---- re-wrap per-worker momentum ----
         if per_worker:
-            new_state = {**new_state}
-            for key in ("momentum", "error"):
-                if key in new_state:
-                    new_state[key] = jax.tree.map(lambda v: v[None],
-                                                  new_state[key])
+            new_state = _per_worker(new_state, lambda v: v[None])
         # ---- metrics: average over replicas ----
         if vote_axes:
             loss = jax.lax.pmean(loss, vote_axes)
