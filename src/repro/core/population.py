@@ -144,12 +144,10 @@ def _partial_counts(eff):
 @jax.jit
 def _partial_bit_counts(eff):
     """Gathered-1-bit partial: per-bit-position set-bit counts of the
-    chunk's packed wire words (the dense tally's inner sum)."""
+    chunk's packed wire words. Bit 32w+j of the wire is eff >= 0 (an
+    abstention binarized to +1), so the counts are taken on the signs."""
     padded, _ = va.pad_last(eff, sc.PACK)
-    wire = sc.pack_signs(padded)                                  # (k, w)
-    shifts = jnp.arange(sc.PACK, dtype=jnp.uint32)
-    bits = (wire[..., None] >> shifts) & jnp.uint32(1)            # (k, w, 32)
-    return jnp.sum(bits.astype(jnp.int32), axis=0)                # (w, 32)
+    return jnp.sum(padded >= 0, axis=0, dtype=jnp.int32).reshape(-1, sc.PACK)
 
 
 @jax.jit
@@ -313,12 +311,8 @@ def streamed_vote(stream, *, strategy: VoteStrategy, codec: str,
         for lo, ids_np in _chunks(stream, chunk_size):
             acc += np.asarray(_partial_bit_counts(eff_of(ids_np)),
                               dtype=np.int64)
-        bcounts = jnp.asarray(acc).astype(jnp.int32)          # (w, 32)
-        maj = (2 * bcounts >= m).astype(jnp.uint32)
-        packed = jnp.zeros(maj.shape[:-1], jnp.uint32)
-        for j in range(sc.PACK):   # unrolled OR (same as the dense tally)
-            packed = packed | (maj[..., j] << jnp.uint32(j))
-        votes = sc.unpack_signs(packed, jnp.int8)[..., :n]
+        votes = jnp.asarray(np.where(2 * acc >= m, 1, -1).reshape(-1)[:n],
+                            jnp.int8)
         # +1-count c -> signed count 2c - M, over the true n coords
         counts = 2 * acc.reshape(-1)[:n] - m
         margin = float(np.mean(np.abs(counts)) / m)

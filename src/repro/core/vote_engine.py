@@ -187,8 +187,8 @@ class Allgather1BitStrategy(VoteStrategyImpl):
 
     pack: bit-pack 32 signs per uint32 word (1 bit/param on the wire);
     exchange: all-gather the packed words over each vote axis; tally:
-    bit-sliced popcount majority across the voter dim; unpack: decode the
-    packed majority (ties -> +1).
+    bit-sliced count of the voters' words (``sc.packed_majority``); unpack:
+    decode the packed majority (ties -> +1).
     """
 
     kind = VoteStrategy.ALLGATHER_1BIT
@@ -213,15 +213,7 @@ class Allgather1BitStrategy(VoteStrategyImpl):
     def tally(self, arrived, n_voters):
         if self._tally_fn is not None:
             return self._tally_fn(arrived)
-        m = arrived.shape[0]
-        shifts = jnp.arange(sc.PACK, dtype=jnp.uint32)
-        bits = (arrived[..., None] >> shifts) & jnp.uint32(1)   # (M, ..., w, 32)
-        counts = jnp.sum(bits.astype(jnp.int32), axis=0)        # (..., w, 32)
-        maj = (2 * counts >= m).astype(jnp.uint32)
-        packed_maj = jnp.zeros(maj.shape[:-1], jnp.uint32)
-        for j in range(sc.PACK):   # unrolled OR (SPMD-partitioner-safe)
-            packed_maj = packed_maj | (maj[..., j] << jnp.uint32(j))
-        return packed_maj
+        return sc.packed_majority(arrived)
 
     def unpack(self, decision, n, dtype):
         return sc.unpack_signs(decision, dtype)[..., :n]
