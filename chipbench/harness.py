@@ -102,12 +102,24 @@ def load_reader(root: Path, name: str):
     return mod.read
 
 
+def use_compile_cache(root: Path) -> None:
+    """Keep every compiled program, however small, in JAX's persistent
+    cache at ``<root>/.jax_cache``."""
+    import jax
+    jax.config.update("jax_compilation_cache_dir", str(root / ".jax_cache"))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+
+
 def reference_config(cell: Cell) -> Dict:
-    """The configuration's published keys with the sizes it assumes and
-    its training settings."""
+    """The configuration's published keys with the sizes it assumes, its
+    training settings, and the traffic's ``vote_strategy`` (the wire
+    whose rule the reference votes by) where it states one."""
     c = {k: v for k, v in cell.config.items() if k != "program"}
     c.update(cell.config.get("assumed_sizes", {}))
     c.update(cell.config["train"])
+    if "vote_strategy" in cell.traffic:
+        c["vote_strategy"] = cell.traffic["vote_strategy"]
     return c
 
 
@@ -371,7 +383,8 @@ def run(root: Path, workload: str, seed: int, seconds: float, trace: bool,
         device_kind=dev.device_kind,
         flops_per_step=family.train_flops_per_step(c, cell.rows, cell.seq),
         optimizer_bytes=opt_bytes.optimizer_bytes(
-            leaves, np.dtype(c["momentum_dtype"]).itemsize, cell.chips),
+            leaves, np.dtype(c["momentum_dtype"]).itemsize, cell.chips,
+            c.get("vote_strategy")),
         trace=None)
     result_device = {"platform": dev.platform, "kind": dev.device_kind,
                      "count": len(devices), "memory_peak_bytes": int(peak)}

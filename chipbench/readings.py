@@ -2,15 +2,18 @@
 """The readings that limits are set from, many seeds in one process.
 
     python chipbench/readings.py --workload <cell> --seeds 1 2 3 \
-        [--control 3] [--fault half_batch]
+        [--control 3] [--reference-wire allgather_1bit] [--fault half_batch]
 
 Per seed: the program's check steps (the same three steps, through the
 same call, as a benchmark run's set-up) and the float32 reference, and
 every number ``yardstick/compare.py`` computes. With ``--control N`` the
 first N seeds also read the control: the reference at ``fp8`` (or at
-``--control-prec``) in the program's place. With ``--fault NAME`` the program runs with that fault
-of ``chipbench/tests/faults.py`` planted. No window is timed. The last
-line is one JSON object of all readings. Needs the cell's chips.
+``--control-prec``) in the program's place. With ``--reference-wire W``
+those N seeds also read the program against the float32 reference voting
+by wire W's rule in place of the cell's (another tie rule). With
+``--fault NAME`` the program runs with that fault of
+``chipbench/tests/faults.py`` planted. No window is timed. The last line
+is one JSON object of all readings. Needs the cell's chips.
 """
 from __future__ import annotations
 
@@ -20,6 +23,7 @@ import importlib
 import json
 import sys
 from pathlib import Path
+from typing import Dict
 
 ROOT = Path(__file__).resolve().parents[1]
 
@@ -43,6 +47,17 @@ def control_readings(root: Path, workload: str, seed: int, devices,
     return compare.numbers(low, ref), cell.limits
 
 
+def wire_readings(prog: Dict, family, c: Dict, wire: str, devices,
+                  seed: int, batches) -> Dict[str, float]:
+    """The numbers of the program's readings `prog` against the float32
+    reference voting by `wire`'s rule in place of the cell's."""
+    from chipbench.reference import common
+    from chipbench.yardstick import compare
+    ref = common.run(family, {**c, "vote_strategy": wire}, "f32", devices,
+                     seed, batches, 1)
+    return compare.numbers(prog, ref)
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)
@@ -51,14 +66,17 @@ def main() -> int:
     ap.add_argument("--fault", default=None)
     ap.add_argument("--control-prec", default="fp8",
                     help="the control's precision (reference/common.mm)")
+    ap.add_argument("--reference-wire", default=None,
+                    help="also read the program against the reference "
+                         "voting by this wire (reference/common.WIRES)")
     args = ap.parse_args()
     sys.path[:0] = [str(ROOT), str(ROOT / "src")]
     import jax
     if jax.devices()[0].platform != "tpu":
         print("readings: needs a TPU", file=sys.stderr)
         return 2
-    jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
     from chipbench import harness
+    harness.use_compile_cache(ROOT)
     from chipbench.reference import common
     from chipbench.tests import faults
     from chipbench.yardstick import compare
@@ -68,7 +86,7 @@ def main() -> int:
              else contextlib.nullcontext)
     with plant():
         prog = harness.Program(cell, jax.devices())
-    out = {"program": {}, "control": {}}
+    out = {"program": {}, "control": {}, "reference_wire": {}}
     for i, seed in enumerate(args.seeds):
         gen = prog.tokens(seed)
         with plant():
@@ -91,7 +109,16 @@ def main() -> int:
             out["control"][seed] = nums
             print(f"control seed={seed} " + " ".join(
                 f"{k}={v!r}" for k, v in nums.items()), flush=True)
-    print(json.dumps({"workload": args.workload, "fault": args.fault, **out}))
+            if args.reference_wire:
+                nums = wire_readings(mine, prog.family, prog.c,
+                                     args.reference_wire, prog.devices, seed,
+                                     batches)
+                out["reference_wire"][seed] = nums
+                print(f"reference_wire={args.reference_wire} seed={seed} "
+                      + " ".join(f"{k}={v!r}" for k, v in nums.items()),
+                      flush=True)
+    print(json.dumps({"workload": args.workload, "fault": args.fault,
+                      "wire": args.reference_wire, **out}))
     return 0
 
 

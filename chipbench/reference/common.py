@@ -3,10 +3,26 @@
 The step that the reference follows is the paper's Algorithm 1 with the
 configuration's settings: per replica r, momentum m_r <- beta m_r +
 (1 - beta) g_r, where g_r is the gradient of the mean next-token cross
-entropy over replica r's rows; the vote is sign(m) on one replica (0 at
-0), and on M > 1 replicas the majority of the binary signs (m >= 0 counts
-as +1) with ties going to +1; every replica then sets
-p <- round(p - lr * vote) to the parameter dtype.
+entropy over replica r's rows; every replica then sets
+p <- round(p - lr * vote) to the parameter dtype. The vote is sign(m) on
+one replica (0 at 0). On M > 1 replicas it follows the cell's wire (the
+configuration's ``vote_strategy``, which the harness takes from the
+traffic file):
+
+* ``allgather_1bit``: the majority of the binary signs (m_r >= 0 counts
+  as +1), ties going to +1;
+* ``psum_int8``: sign(sum_r sign(m_r)) of the ternary signs, 0 on a tie
+  or where every replica abstains (m_r = 0).
+
+Any other wire, or none, on M > 1 replicas is an error.
+
+The model's ends: the input is table[tokens] of ``embed.table``, times
+``embedding_multiplier`` where the configuration gives one; the logits
+come from ``unembed.table`` where the family's ``param_shapes`` has one
+(an untied head, whose gradient goes into its own momentum) and else
+from ``embed.table`` (tied), divided by ``logits_scaling`` where the
+configuration gives one. Without those keys nothing is multiplied or
+divided.
 
 Memory is bounded by working in blocks: one block of rows at a time,
 and within it one layer at a time. The forward keeps each layer's input;
@@ -26,7 +42,7 @@ does.
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable, Dict, List, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -114,6 +130,41 @@ def cross_entropy(logits: jax.Array, tokens: jax.Array) -> jax.Array:
     return jnp.mean(jax.nn.logsumexp(lg, axis=-1) - gold)
 
 
+def _bit_ballot(m: jax.Array) -> jax.Array:
+    return (m >= 0).astype(jnp.int8)
+
+
+def _bit_tally(ballots: Sequence[jax.Array]) -> jax.Array:
+    ones = sum(b.astype(jnp.int32) for b in ballots)
+    return jnp.where(2 * ones >= len(ballots), 1, -1).astype(jnp.int8)
+
+
+def _ternary_ballot(m: jax.Array) -> jax.Array:
+    return jnp.sign(m).astype(jnp.int8)
+
+
+def _count_tally(ballots: Sequence[jax.Array]) -> jax.Array:
+    return jnp.sign(sum(b.astype(jnp.int32) for b in ballots)
+                    ).astype(jnp.int8)
+
+
+# wire -> (ballot, tally): one replica's momentum leaf to the int8 ballot
+# it sends, and the replicas' ballots to the int8 vote
+WIRES: Dict[str, Tuple[Callable, Callable]] = {
+    "allgather_1bit": (_bit_ballot, _bit_tally),
+    "psum_int8": (_ternary_ballot, _count_tally),
+}
+
+
+def vote_rule(wire: Optional[str]) -> Tuple[Callable, Callable]:
+    """(ballot, tally) of the vote on several replicas by `wire`."""
+    if wire not in WIRES:
+        raise ValueError(f"the reference follows no vote of several "
+                         f"replicas on the wire {wire!r}; it knows "
+                         f"{sorted(WIRES)}")
+    return WIRES[wire]
+
+
 @dataclasses.dataclass(frozen=True)
 class Block:
     """One kind of layer: `fn(prec, c, lp, h) -> h` with the residual
@@ -132,12 +183,17 @@ def _layer_params(P: Dict, i, stacked: bool) -> Dict:
 
 class Reference:
     """The reference of one configuration `c` (its file's keys) at one
-    precision, over `replicas` replicas placed one per device."""
+    precision, over replicas placed one per device of `devices`."""
 
     def __init__(self, family, c: Dict, prec: str, devices: Sequence):
         self.f, self.c, self.prec = family, c, prec
         self.devices = list(devices)
         self.shapes = family.param_shapes(c)
+        head_table = "unembed.table" if "unembed.table" in self.shapes \
+            else "embed.table"
+        self.top_names = ("final_norm.scale", head_table)
+        emb_mult = c.get("embedding_multiplier")
+        logits_scaling = c.get("logits_scaling")
         self.dtype = jnp.dtype(c["dtype"])
         self.beta = float(c["momentum"])
         self.lr = float(c["learning_rate"])
@@ -169,7 +225,9 @@ class Reference:
         def head(P, M, h, tokens, coef):
             def f(top, h_):
                 x = rms_norm(h_, top["final_norm.scale"], eps)
-                logits = mm(prec, "bsd,vd->bsv", x, top["embed.table"])
+                logits = mm(prec, "bsd,vd->bsv", x, top[head_table])
+                if logits_scaling is not None:
+                    logits = logits / jnp.float32(logits_scaling)
                 return cross_entropy(logits, tokens)
             top = {k: P[k].astype(jnp.float32) for k in P}
             loss, vjp = jax.vjp(f, top, h)
@@ -177,21 +235,26 @@ class Reference:
             M = {k: M[k] + (coef * dtop[k]).astype(M[k].dtype) for k in M}
             return loss, dh, M
 
+        def embed(T, tokens):
+            h = T[tokens].astype(jnp.float32)
+            if emb_mult is not None:
+                h = h * jnp.float32(emb_mult)
+            return stream(prec, h)
+
         def embed_bwd(M, tokens, dh, coef):
+            if emb_mult is not None:
+                dh = dh * jnp.float32(emb_mult)
             return M.at[tokens].add((coef * dh).astype(M.dtype))
 
-        self._embed = jax.jit(
-            lambda T, tokens: stream(prec, T[tokens].astype(jnp.float32)))
+        self._embed = jax.jit(embed)
         self._head = jax.jit(head, donate_argnums=(1,))
         self._embed_bwd = jax.jit(embed_bwd, donate_argnums=(0,))
         self._scale = jax.jit(
             lambda M, b: jax.tree.map(lambda m: m * b.astype(m.dtype), M),
             donate_argnums=(0,))
-        self._bits = jax.jit(lambda m: (m >= 0).astype(jnp.int8))
-        self._majority = jax.jit(
-            lambda bits: jnp.where(
-                2 * sum(b.astype(jnp.int32) for b in bits) >= len(bits),
-                1, -1).astype(jnp.int8))
+        if len(self.devices) > 1:
+            ballot, tally = vote_rule(c.get("vote_strategy"))
+            self._ballot, self._tally = jax.jit(ballot), jax.jit(tally)
         self._sign = jax.jit(lambda M: {k: jnp.sign(v).astype(jnp.int8)
                                         for k, v in M.items()})
         self._update = jax.jit(self._update_fn, donate_argnums=(0,))
@@ -225,7 +288,7 @@ class Reference:
         losses = []
         groups = {kind: (self._leaf_group(P, blk.prefix), blk.prefix)
                   for kind, blk in self.f.BLOCKS.items()}
-        top_names = ("final_norm.scale", "embed.table")
+        top_names = self.top_names
         for b in range(n_blocks):
             tok = jax.device_put(rows[b * block_rows:(b + 1) * block_rows],
                                  device)
@@ -264,12 +327,12 @@ class Reference:
         else:
             V = [dict() for _ in range(R)]
             for k in Ms[0]:
-                bits = [jax.device_put(self._bits(Ms[r][k]), self.devices[0])
-                        for r in range(R)]
-                vote = self._majority(bits)
-                del bits
+                ballots = [jax.device_put(self._ballot(Ms[r][k]),
+                                          self.devices[0]) for r in range(R)]
+                decision = self._tally(ballots)
+                del ballots
                 for r in range(R):
-                    V[r][k] = jax.device_put(vote, self.devices[r])
+                    V[r][k] = jax.device_put(decision, self.devices[r])
         for r in range(R):
             Ps[r] = self._update(Ps[r], V[r])
         del V

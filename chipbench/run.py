@@ -40,11 +40,8 @@ def main() -> int:
         print(f"chipbench: needs a TPU, JAX found {devices[0].platform!r}",
               file=sys.stderr)
         return 2
-    jax.config.update("jax_compilation_cache_dir", str(ROOT / ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-
     from chipbench import harness
+    harness.use_compile_cache(ROOT)
     cell = harness.load_cell(ROOT, args.workload)
     if len(devices) < cell.chips:
         print(f"chipbench: {args.workload} needs {cell.chips} chips, JAX "

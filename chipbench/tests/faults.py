@@ -44,18 +44,23 @@ def half_batch():
     return _patched(M, "loss_fn", loss_fn)
 
 
+@contextlib.contextmanager
 def exchange_left_out():
-    """The 1-bit wire's all-gather is skipped: each replica tallies its
-    own packed signs in place of everyone's."""
+    """The wire's exchange is skipped: on the 1-bit wire each replica
+    tallies its own packed signs in place of everyone's (no all-gather),
+    on the int8 wire it takes its own signs as the count (no all-reduce)."""
     import jax.numpy as jnp
 
     from repro.configs.base import VoteStrategy
     from repro.core import vote_engine as ve
-    impl = ve.STRATEGIES[VoteStrategy.ALLGATHER_1BIT]
 
-    def exchange(wire, axes):
+    def gather(wire, axes):
         return jnp.repeat(wire[None], ve.num_voters(axes), axis=0)
-    return _patched(impl, "exchange", exchange)
+    with _patched(ve.STRATEGIES[VoteStrategy.ALLGATHER_1BIT], "exchange",
+                  gather), \
+            _patched(ve.STRATEGIES[VoteStrategy.PSUM_INT8], "exchange",
+                     lambda wire, axes: wire):
+        yield
 
 
 FAULTS = {"state_unchanged": state_unchanged, "half_batch": half_batch,

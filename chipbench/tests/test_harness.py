@@ -86,6 +86,57 @@ def test_four_replicas_and_the_exchange_left_out(root):
     assert sound and not broken, checks
 
 
+_INT8 = """
+import json, sys, time
+sys.path[:0] = [{repo!r}, {src!r}]
+from pathlib import Path
+import jax
+from chipbench import harness, readings
+from chipbench.tests import faults
+root = Path({root!r})
+cell = "zamba2-tiny.dp4-int8-t4-s64"
+def run():
+    return harness.run(root, cell, 1, 0.5, False, time.perf_counter(),
+                       jax.devices())
+out = {{"sound": run()}}
+for name in ("exchange_left_out", "state_unchanged", "half_batch"):
+    with faults.FAULTS[name]():
+        out[name] = run()
+prog = harness.Program(harness.load_cell(root, cell), jax.devices())
+gen = prog.tokens(1)
+params, opt_state = prog.start(1)
+_, _, mine = prog.check_steps(params, opt_state, gen, 1)
+batches = [gen.batch(s) for s in range(harness.CHECK_STEPS)]
+out["tie_plus_one"] = readings.wire_readings(
+    mine, prog.family, prog.c, "allgather_1bit", prog.devices, 1, batches)
+print(json.dumps(out))
+"""
+
+
+def test_int8_wire_on_four_replicas(root):
+    """The int8 wire's cell is correct with every replica alike; each
+    fault it can have makes it incorrect; and the reference voting by the
+    1-bit rule (ties to +1) in place of the wire's (ties abstain) fails
+    its limit on the change of the parameters."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    code = _INT8.format(repo=str(REPO), src=str(REPO / "src"),
+                        root=str(root))
+    res = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=900)
+    assert res.returncode == 0, res.stderr[-3000:]
+    out = json.loads(res.stdout.strip().splitlines()[-1])
+    sound = out.pop("sound")
+    assert sound["correct"], sound["checks"]
+    assert sound["checks"]["replicas_differ"]["value"] == 0
+    tie = out.pop("tie_plus_one")
+    limits = harness.load_cell(root, "zamba2-tiny.dp4-int8-t4-s64").limits
+    assert tie["delta_norm_gap"] > limits["delta_norm_gap"], tie
+    assert tie["grad_norm_gap"] == sound["checks"]["grad_norm_gap"]["value"]
+    for name, broken in out.items():
+        assert not broken["correct"], (name, broken["checks"])
+
+
 def test_no_tpu_exits_before_any_phase():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     res = subprocess.run(

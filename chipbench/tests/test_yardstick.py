@@ -54,10 +54,15 @@ def test_family_flops_sum_their_blocks():
 
 def test_optimizer_bytes_by_hand():
     leaves = [(10, 2), (3, 4)]
-    # one replica: 10*(2 + 8 + 4) + 3*(4 + 8 + 8) = 200
-    assert opt_bytes.optimizer_bytes(leaves, 4, 1) == 200
-    # four: + 13 * (1 + 3 + 4) / 8 = 13
-    assert opt_bytes.optimizer_bytes(leaves, 4, 4) == 213
+    # one replica: 10*(2 + 8 + 4) + 3*(4 + 8 + 8) = 200, whatever the wire
+    assert opt_bytes.optimizer_bytes(leaves, 4, 1, None) == 200
+    assert opt_bytes.optimizer_bytes(leaves, 4, 1, "psum_int8") == 200
+    # four on the 1-bit wire: + 13 * (1 + 3 + 4) / 8 = 13
+    assert opt_bytes.optimizer_bytes(leaves, 4, 4, "allgather_1bit") == 213
+    # four on the int8 wire: + 13 * (1 + 1 + 1) = 39
+    assert opt_bytes.optimizer_bytes(leaves, 4, 4, "psum_int8") == 239
+    with pytest.raises(ValueError, match="None"):
+        opt_bytes.optimizer_bytes(leaves, 4, 4, None)
 
 
 def test_tokens_are_a_function_of_seed_and_step():
@@ -145,3 +150,17 @@ def test_init_rules(rule):
         # out_proj over sqrt(64 layers)
         assert abs(f["layers.mamba_out_proj"].std() * np.sqrt(3 * 1024)
                    * 8 - 1) < 0.02
+
+
+def test_untied_head_draws_with_fan_in_d():
+    import jax
+
+    from chipbench.yardstick import init as yinit
+    c = {"dtype": "float32", "init": _KAIMING}
+    shapes = {"unembed.table": (4096, 64), "layers.w": (4096, 64)}
+    P = yinit.init_params(shapes, 11, c, jax.devices()[0])
+    head = np.asarray(P["unembed.table"])
+    # U(+-1/sqrt(64)), not U(+-1/sqrt(4096)) as a (fan-in, out) leaf draws
+    assert np.abs(head).max() <= 1 / 8
+    assert abs(head.std() * np.sqrt(3 * 64) - 1) < 0.02
+    assert abs(np.asarray(P["layers.w"]).std() * np.sqrt(3 * 4096) - 1) < 0.02

@@ -15,6 +15,7 @@ CELLS = {
     "zamba2-tiny.t4-s64": ("zamba2-tiny", "t4-s64", 1),
     "mamba2-tiny.t4-s64": ("mamba2-tiny", "t4-s64", 1),
     "zamba2-tiny.dp4-1bit-t4-s64": ("zamba2-tiny", "dp4-1bit-t4-s64", 4),
+    "zamba2-tiny.dp4-int8-t4-s64": ("zamba2-tiny", "dp4-int8-t4-s64", 4),
 }
 
 

@@ -13,14 +13,16 @@ names and each draws from its own split of the key. By name:
 * ``embed.table``: N(0, ``embed_std``²);
 * ``*_conv_w`` and ``*_conv_b``: by ``conv``, biases 0 under ``normal``
   and of fan-in ``conv_fan_in`` under ``kaiming_uniform``;
+* ``unembed.table`` (an untied head, stored (vocabulary, d) as the
+  source's output layer): by ``linear``, of fan-in d, its last axis;
 * every other leaf (a projection, stored fan-in first): by ``linear``;
   ``*out_proj`` divided by √``out_proj_rescale`` where that is given.
 
 A rule is ``normal`` (N(0, ``std``²)) or ``kaiming_uniform`` (PyTorch's
 default for a Linear or a Conv1d: U(±1/√fan_in), fan_in the
-second-to-last dimension). Values are drawn in float32 and rounded to
-the parameter dtype. The program under test is given these weights; the
-reference makes them here itself.
+second-to-last dimension, or the last of ``unembed.table``). Values are
+drawn in float32 and rounded to the parameter dtype. The program under
+test is given these weights; the reference makes them here itself.
 """
 from __future__ import annotations
 
@@ -77,7 +79,8 @@ def _leaf(name: str, shape: Tuple[int, ...], key, dtype, rule: Dict
             return jnp.zeros(shape, dtype)
         fan_in = rule["conv_fan_in"]
         return _draw(rule["conv"], rule, shape, key, fan_in).astype(dtype)
-    fan_in = shape[-2] if len(shape) >= 2 else shape[-1]
+    fan_in = shape[-1] if len(shape) < 2 or name == "unembed.table" \
+        else shape[-2]
     kind = rule["conv"] if name.endswith("_conv_w") else rule["linear"]
     w = _draw(kind, rule, shape, key, fan_in)
     if name.endswith("out_proj") and "out_proj_rescale" in rule:
